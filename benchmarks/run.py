@@ -156,6 +156,8 @@ def main() -> None:
                     help="figure mode: e.g. fig05 | fig12 | kernels | all")
     args = ap.parse_args()
 
+    from repro import compile_cache
+    compile_cache.enable()
     if args.fig is not None and (args.scenario is not None or args.check
                                  or args.list):
         ap.error("--fig is figure mode; it cannot be combined with "

@@ -42,6 +42,13 @@ def probe_positions(keys: jax.Array, k: int, bits: int) -> jax.Array:
     return pos % np.uint32(bits)
 
 
+# keys hashed per scatter when building a filter: a scatter of
+# n * k positions costs the TPU a sort of them, so a deepest-level run
+# (16M keys at the one-chip paper geometry) is built in slices of this
+# many keys, keeping that sort's temporaries at tens of MiB
+BUILD_CHUNK = 1 << 20
+
+
 def bloom_build(keys: jax.Array, valid: jax.Array, words: int, k: int,
                 bits: int | None = None) -> jax.Array:
     """Build a (words,) uint32 filter over `keys` where `valid`.
@@ -51,16 +58,29 @@ def bloom_build(keys: jax.Array, valid: jax.Array, words: int, k: int,
     its densest allocation and passes the current allocation's smaller
     `bits` here — probe positions then stay inside [0, bits) and the
     tail words are never touched, so probe (with the same `bits`) and
-    build agree."""
+    build agree. Keys are hashed `BUILD_CHUNK` at a time into the one
+    bitset (setting a bit is idempotent, so the filter does not depend
+    on the slicing; a run of at most `BUILD_CHUNK` keys is one slice)."""
     if bits is None:
         bits = words * 32
     assert bits <= words * 32, f"effective bits {bits} > {words} words"
     bits_phys = words * 32
-    pos = probe_positions(keys, k, bits).astype(jnp.int32)
-    # invalid keys -> out-of-range position, dropped by the scatter
-    pos = jnp.where(valid[..., None], pos, bits_phys)
-    hot = jnp.zeros((bits_phys,), jnp.bool_).at[pos.reshape(-1)].set(
-        True, mode="drop")
+
+    def set_bits(hot, ks, ok):
+        pos = probe_positions(ks, k, bits).astype(jnp.int32)
+        # invalid keys -> out-of-range position, dropped by the scatter
+        pos = jnp.where(ok[..., None], pos, bits_phys)
+        return hot.at[pos.reshape(-1)].set(True, mode="drop")
+
+    n = keys.shape[0]
+    chunk = max(1, min(n, BUILD_CHUNK))
+    n_chunks = -(-n // chunk)
+    pad = n_chunks * chunk - n
+    ks = jnp.pad(keys, (0, pad)).reshape(n_chunks, chunk)
+    ok = jnp.pad(valid, (0, pad)).reshape(n_chunks, chunk)
+    hot = jax.lax.fori_loop(0, n_chunks,
+                            lambda i, h: set_bits(h, ks[i], ok[i]),
+                            jnp.zeros((bits_phys,), jnp.bool_))
     weights = jnp.left_shift(np.uint32(1), jnp.arange(32, dtype=jnp.uint32))
     return (hot.reshape(words, 32).astype(jnp.uint32) * weights).sum(
         axis=1, dtype=jnp.uint32

@@ -8,8 +8,8 @@ lane separate from the merge lanes.
   * a run is a dense sorted (keys, vals, wts, seqs) quad padded with
     KEY_EMPTY;
   * HeapMerge (paper 2.5, O(n log k) serial heap) becomes either
-      - a multi-operand stable `lax.sort` on (key, seq) — XLA's bitonic
-        network, O(n log^2 n) comparisons but fully parallel; or
+      - a `lax.sort` on (key, seq) — XLA's sort network, O(n log^2 n)
+        comparisons but fully parallel; or
       - `merge_kway_ranked` — the rank-merge: every element's output slot is
         its own index plus its rank in every other run, computed with
         vectorized binary searches. O(n log k) *work*, data-independent
@@ -43,10 +43,20 @@ _KEY_MIN = np.int32(np.iinfo(np.int32).min)
 
 
 def sort_records(keys, vals, wts, seqs):
-    """Stable lexicographic sort by (key, seq); vals/wts ride as payload.
-    Sentinels sort to the end. Returns (keys, vals, wts, seqs)."""
-    keys, seqs, vals, wts = jax.lax.sort((keys, seqs, vals, wts), num_keys=2)
-    return keys, vals, wts, seqs
+    """Lexicographic sort by (key, seq); vals/wts ride as payload.
+    Sentinels sort to the end. Returns (keys, vals, wts, seqs).
+
+    Every live record carries its own seqno, so (key, seq) pairs are
+    unique and an unstable sort orders them exactly as a stable one
+    would; only identical padding lanes can tie. The payload lanes ride
+    a gather through the sorted source index instead of entering the
+    sort: the TPU compiler's time for a sort grows with its operand
+    count and with stability, and at deployment widths that time is
+    what a cold engine start pays (DESIGN.md §13's Ghost shape)."""
+    idx = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    keys, seqs, idx = jax.lax.sort((keys, seqs, idx), num_keys=2,
+                                   is_stable=False)
+    return keys, vals[idx], wts[idx], seqs
 
 
 def survivor_mask(keys: jax.Array, wts: jax.Array,
@@ -63,12 +73,14 @@ def survivor_mask(keys: jax.Array, wts: jax.Array,
 
 
 def compact(keys, vals, wts, seqs, valid):
-    """Stable-partition valid elements to the front; pad the rest.
+    """Move the valid elements of a key-sorted run to the front; pad the
+    rest. Returns (keys, vals, wts, seqs, count).
 
-    Returns (keys, vals, wts, seqs, count). Order among valid elements is
-    preserved (stable argsort on the invalid flag).
-    """
-    order = jnp.argsort((~valid).astype(jnp.int32), stable=True)
+    Valid keys are unique (the survivor mask keeps one record per key),
+    so re-sorting with every invalid lane's key set to KEY_EMPTY keeps
+    the valid elements in their order — a stable partition bought with
+    one unstable two-operand sort (see `sort_records` on why)."""
+    order = _partition_order(keys, valid)
     ok = valid[order]
     keys = jnp.where(ok, keys[order], KEY_EMPTY)
     vals = jnp.where(ok, vals[order], 0)
@@ -77,11 +89,20 @@ def compact(keys, vals, wts, seqs, valid):
     return keys, vals, wts, seqs, valid.sum(dtype=jnp.int32)
 
 
+def _partition_order(keys, valid):
+    """Gather order that moves the valid lanes of a key-sorted array with
+    unique valid keys to the front, in key order."""
+    masked = jnp.where(valid, keys, KEY_EMPTY)
+    idx = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    _, order = jax.lax.sort((masked, idx), num_keys=1, is_stable=False)
+    return order
+
+
 def merge_runs(keys2d, vals2d, wts2d, seqs2d, drop_annihilated: bool):
     """Merge k sorted runs (k, cap) -> one compacted run (k*cap,).
 
-    Sort-based path (XLA bitonic network) over the (key, weight, seq,
-    source-index) lanes only — the payload lane never enters the sort.
+    Sort-based path (XLA sort network) over the (key, seq, source-index)
+    lanes only — weights and the payload lane never enter the sort.
     The per-key weight sum telescopes to the newest record (the sort is
     keyed on (key, seq) and dedup keeps the last copy — the paper's
     "highest-ranked run's value is written" rule, with run recency
@@ -89,11 +110,13 @@ def merge_runs(keys2d, vals2d, wts2d, seqs2d, drop_annihilated: bool):
     surviving rows' source indices in one final pass (the Ghost
     property). Returns (keys, vals, wts, seqs, count).
     """
-    k, w, s = keys2d.reshape(-1), wts2d.reshape(-1), seqs2d.reshape(-1)
+    k, s = keys2d.reshape(-1), seqs2d.reshape(-1)
     idx = jnp.arange(k.shape[0], dtype=jnp.int32)
-    k, s, w, idx = jax.lax.sort((k, s, w, idx), num_keys=2)
+    # (key, seq) is unique per live record: unstable == stable here
+    k, s, idx = jax.lax.sort((k, s, idx), num_keys=2, is_stable=False)
+    w = wts2d.reshape(-1)[idx]
     valid = survivor_mask(k, w, drop_annihilated)
-    order = jnp.argsort((~valid).astype(jnp.int32), stable=True)
+    order = _partition_order(k, valid)
     ok = valid[order]
     keys = jnp.where(ok, k[order], KEY_EMPTY)
     wts = jnp.where(ok, w[order], 0)

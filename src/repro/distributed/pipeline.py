@@ -38,13 +38,9 @@ def gpipe_forward(stage_fn, stage_params, x_micro, mesh, axis: str = "pipe"):
         pl = jax.tree.map(lambda p: p[0], params_local)
         stage = jax.lax.axis_index(axis)
         # carries become device-varying after the first ppermute; mark them
-        # varying from the start so the loop carry type is stable (pcast
-        # exists only on newer jax; older shard_map needs no marking)
-        pcast = getattr(jax.lax, "pcast", None)
-        varying = ((lambda v: pcast(v, axis, to="varying")) if pcast
-                   else (lambda v: v))
-        buf = varying(jnp.zeros_like(xs[0]))
-        outs = varying(jnp.zeros_like(xs))
+        # varying from the start so the loop carry type is stable
+        buf = jax.lax.pcast(jnp.zeros_like(xs[0]), axis, to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs), axis, to="varying")
 
         def tick(t, carry):
             buf, outs = carry

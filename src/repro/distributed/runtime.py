@@ -10,10 +10,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 _DP: tuple[str, ...] | None = None
 _MODEL: str | None = None

@@ -67,7 +67,7 @@ class CompactionPolicy:
     def spill_sizes(self, p: SLSMParams) -> tuple:
         """Every distinct `runs_to_spill` value this policy can produce.
 
-        The merge scheduler's warm() precompiles one spill program per
+        The engine's warm() precompiles one spill program per
         (level, size, annihilation-flag) — `n_merge` is a jit-static
         argument, so each size is its own compiled program and an
         unwarmed size would stall the first insert chunk that needs it.
@@ -201,6 +201,26 @@ merge_level_down = functools.partial(
         merge_level_down_impl)
 
 
+def compaction_rows(p: SLSMParams) -> tuple:
+    """``(rows, width)`` of the deepest compaction's merge input.
+
+    Slot 0 of the deepest level may hold a whole earlier compaction
+    (`level_cap` rows), but every other slot was filled by one spill
+    from the level above — at most D * level_cap(last - 1) rows — or,
+    when the deepest level is level 0, by one flush (at most
+    runs_merged * Rn rows). So the merge reads slot 0 cut into sorted
+    pieces of that width plus the first `width` lanes of each other
+    slot: the rest of their capacity is always padding. At the one-chip
+    paper geometry that is 31.5M lanes instead of D * level_cap =
+    323.6M, which is what makes the compaction's temporaries small."""
+    last = p.max_levels - 1
+    cap = p.level_cap(last)
+    width = (p.D * p.level_cap(last - 1) if last > 0
+             else p.runs_merged * p.Rn)
+    width = min(width, cap)
+    return -(-cap // width) + p.D - 1, width
+
+
 def compact_last_level_impl(p: SLSMParams, state: SLSMState):
     """In-place compaction of the deepest level: merge all D runs into slot 0.
 
@@ -208,11 +228,24 @@ def compact_last_level_impl(p: SLSMParams, state: SLSMState):
     2.5: 'keys flagged for delete are not written ... at all' — the
     newest record's weight sums to <= 0 and the row is dropped).
     Returns (state, raw_count); the host raises if raw_count exceeds the
-    deepest run capacity (the TPU analogue of running out of disk)."""
+    deepest run capacity (the TPU analogue of running out of disk).
+    Donates the state like the other steps, and merges only the lanes a
+    run can occupy (`compaction_rows`): at deployment geometry a second
+    state, or a merge over every slot's full capacity, does not fit one
+    chip."""
     be = get_backend(p.backend)
     last = p.max_levels - 1
     lv = state.levels[last]
-    k, v, w, s, cnt = be.merge_runs(lv.keys, lv.vals, lv.wts, lv.seqs, True)
+    rows, width = compaction_rows(p)
+    head_pad = (rows - p.D + 1) * width - p.level_cap(last)
+
+    def pieces(a, fill):
+        head = jnp.concatenate([a[0], jnp.full((head_pad,), fill, a.dtype)])
+        return jnp.concatenate([head.reshape(-1, width), a[1:, :width]])
+
+    k, v, w, s, cnt = be.merge_runs(pieces(lv.keys, KEY_EMPTY),
+                                    pieces(lv.vals, 0), pieces(lv.wts, 0),
+                                    pieces(lv.seqs, 0), True)
     k, v, w, s, filt, fences, mn, mx = index_new_run(p, last, k, v, w, s, cnt)
     fresh = empty_level(p, last)
     fresh = set_level_run(fresh, 0, k, v, w, s,
@@ -222,4 +255,4 @@ def compact_last_level_impl(p: SLSMParams, state: SLSMState):
 
 
 compact_last_level = functools.partial(
-    jax.jit, static_argnums=0)(compact_last_level_impl)
+    jax.jit, static_argnums=0, donate_argnums=1)(compact_last_level_impl)

@@ -31,6 +31,7 @@ from repro.engine.batching import (ADAPTIVE_BUCKETS, RANGE_BUCKETS,
 from repro.engine.compaction import (CompactionPolicy, LevelingPolicy,
                                      TieringPolicy)
 from repro.engine.memtable import init_state, stage_append
+from repro.engine.precompile import compile_programs, i32, state_shapes
 from repro.engine.read_path import (aggregate_many, level_probe_stats,
                                     lookup_batch, lookup_many, range_many,
                                     range_query)
@@ -93,6 +94,8 @@ class SLSM:
         self.policy = policy or TieringPolicy()
         self.policy.validate(self.p)
         self.state = init_state(self.p)
+        # why `state` is gone, once a declared capacity error consumed it
+        self.state_lost: str | None = None
         # p_active = the tuner's current allocation applied to p (same
         # physical geometry, possibly different effective filter/buffer/
         # fence view); == p forever under static tuning (DESIGN.md §9)
@@ -125,6 +128,19 @@ class SLSM:
         # leader from diverging from the cluster
         self.replication = None
         self.fenced = False
+
+    @property
+    def state(self):
+        """The device state pytree. Raises once a deepest-level overflow
+        consumed it (the compaction donates its input — see
+        scheduler.MergeScheduler.run_step)."""
+        if self._state is None:
+            raise RuntimeError(self.state_lost)
+        return self._state
+
+    @state.setter
+    def state(self, value) -> None:
+        self._state = value
 
     # -- write path -------------------------------------------------------
     def _guard_writes(self) -> None:
@@ -208,43 +224,47 @@ class SLSM:
         """Precompile the engine's full maintenance program set, so no
         insert chunk ever pays a first-use jit compile (the other — and
         at bench scale dominant — write-stall source besides cascade
-        work; see MergeScheduler.warm). Optional; call before
+        work; see MergeScheduler.programs). Optional; call before
         latency-sensitive serving.
 
         Also precompile the *read* programs (batched lookup per `bucket`,
-        the single-key shape, the range-scan grid — `RANGE_BUCKETS`
-        batched widths plus the single-scan program) for every
-        levels-structure the engine can grow into, so mid-stream level
-        materialization never drops a compile into a live lookup or
-        scan. With adaptive tuning the grid spans every preset
+        the single-key shape, the range-scan and aggregate grids —
+        `RANGE_BUCKETS` batched widths plus the single-scan program) for
+        every levels-structure the engine can grow into, so mid-stream
+        level materialization never drops a compile into a live lookup
+        or scan. With adaptive tuning the grid spans every preset
         allocation — a retune swaps jit-static params, and without this
         the first read after a switch would pay the compile the pacing
-        budget cannot flatten — plus the probe-telemetry pass."""
-        self.scheduler.warm()
-        if self.tuner.enabled:
-            param_sets = [alloc.apply(self.p)
-                          for alloc in self.tuner.presets.values()]
-        else:
-            param_sets = [self.p]
+        budget cannot flatten — plus the probe-telemetry pass.
+
+        Compiles from shapes alone, concurrently, and touches no device
+        memory (`precompile.compile_programs`)."""
+        progs = self.scheduler.programs()
         skip = self.tuner.enabled
-        outs = []
-        for pa in param_sets:
+        for pa in self._param_sets():
             for n_levels in range(self.p.max_levels + 1):
-                st = init_state(pa, n_levels)
+                st = state_shapes(pa, n_levels)
                 for b in buckets:
-                    qs = jnp.zeros((b,), jnp.int32)
-                    outs.append(lookup_many(pa, st, qs, jnp.int32(0),
-                                            False, skip))
-                outs.append(lookup_batch(pa, st, jnp.zeros((1,), jnp.int32),
-                                         False, skip))
+                    progs.append((lookup_many,
+                                  (pa, st, i32(b), i32(), False, skip)))
+                progs.append((lookup_batch, (pa, st, i32(1), False, skip)))
                 for b in RANGE_BUCKETS:
-                    z = jnp.zeros((b,), jnp.int32)
-                    outs.append(range_many(pa, st, z, z, jnp.int32(0)))
-                outs.append(range_query(pa, st, jnp.int32(0), jnp.int32(0)))
+                    for fn in (range_many, aggregate_many):
+                        progs.append((fn, (pa, st, i32(b), i32(b), i32())))
+                progs.append((range_query, (pa, st, i32(), i32())))
                 if skip:
-                    outs.append(level_probe_stats(
-                        pa, st, jnp.zeros((PROBE_SAMPLE,), jnp.int32)))
-        jax.block_until_ready(outs)
+                    progs.append((level_probe_stats,
+                                  (pa, st, i32(PROBE_SAMPLE))))
+        compile_programs(progs)
+
+    def _param_sets(self) -> list:
+        """Every static parameter set the engine can dispatch under: the
+        configured one, or each preset allocation of the adaptive tuner
+        (an allocation is a jit-static argument)."""
+        if self.tuner.enabled:
+            return [alloc.apply(self.p)
+                    for alloc in self.tuner.presets.values()]
+        return [self.p]
 
     # -- read path ----------------------------------------------------------
     def _on_reads(self, qs: np.ndarray) -> None:
@@ -531,24 +551,17 @@ class SLSM:
         read grid — after this, steady-state serving windows never JIT
         (`run_tape` only ever dispatches these shapes). Call alongside
         `warm()` before latency-sensitive serving."""
-        if self.tuner.enabled:
-            param_sets = [alloc.apply(self.p)
-                          for alloc in self.tuner.presets.values()]
-        else:
-            param_sets = [self.p]
         skip = self.tuner.enabled
-        outs = []
-        for pa in param_sets:
+        progs = []
+        for pa in self._param_sets():
             for n_levels in range(self.p.max_levels + 1):
+                st = state_shapes(pa, n_levels)
                 for t in buckets:
-                    st = init_state(pa, n_levels)
-                    outs.append(TP.tape_exec(
-                        pa, st, jnp.zeros((t,), jnp.int32),
-                        jnp.full((t, pa.Rn), KEY_EMPTY, jnp.int32),
-                        jnp.zeros((t, pa.Rn), jnp.int32),
-                        jnp.zeros((t, pa.Rn), jnp.int32),
-                        jnp.zeros((t,), jnp.int32), False, skip))
-        jax.block_until_ready(outs)
+                    progs.append((TP.tape_exec,
+                                  (pa, st, i32(t), i32(t, pa.Rn),
+                                   i32(t, pa.Rn), i32(t, pa.Rn), i32(t),
+                                   False, skip)))
+        compile_programs(progs)
 
     # -- tuner plumbing ----------------------------------------------------
     def sample_probe_stats(self) -> None:

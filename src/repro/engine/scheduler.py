@@ -61,14 +61,15 @@ from __future__ import annotations
 
 from typing import List, NamedTuple, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.params import SLSMParams
 from repro.engine.compaction import (CompactionPolicy, compact_last_level,
-                                     merge_buffer_to_level0, merge_level_down)
+                                     compaction_rows, merge_buffer_to_level0,
+                                     merge_level_down)
 from repro.engine.levels import empty_level
-from repro.engine.memtable import init_state, seal_run, stage_append
+from repro.engine.memtable import seal_run, stage_append
+from repro.engine.precompile import i32, state_shapes
 
 SEAL, FLUSH, SPILL, COMPACT = "seal", "flush", "spill", "compact"
 RETUNE = "retune"
@@ -137,13 +138,15 @@ def step_ready(kind: str, level: int, occ: Occupancy, p: SLSMParams,
 def step_cost(kind: str, level: int, p: SLSMParams) -> int:
     """Device-op cost of one step, in elements touched by its merge — the
     uniform cost axis the pacing trades against (a seal is ~Rn, the
-    deepest compaction is D * level_cap(last): orders of magnitude)."""
+    deepest compaction reads (2D-1) * D * level_cap(last-1) lanes at
+    m=1 — compaction.compaction_rows: orders of magnitude)."""
     if kind == SEAL:
         return p.Rn
     if kind == FLUSH:
         return p.runs_merged_eff * p.Rn
     if kind == COMPACT:
-        return p.D * p.level_cap(p.max_levels - 1)
+        rows, width = compaction_rows(p)
+        return rows * width
     if kind == RETUNE:   # every resident filter is rebuilt from its keys
         return p.R * p.Rn + sum(p.D * p.level_cap(lvl)
                                 for lvl in range(p.max_levels))
@@ -290,14 +293,21 @@ class MergeScheduler:
         else:   # COMPACT
             last = p.max_levels - 1
             rows_in = int(jnp.sum(drv.state.levels[last].counts))
-            new_state, raw = compact_last_level(p, drv.state)
+            # the compaction donates the state (at deployment geometry a
+            # second copy of it does not fit beside the merge), so an
+            # overflow leaves nothing to roll back to: the engine drops
+            # its state and every later call raises the same error
+            drv.state, raw = compact_last_level(p, drv.state)
             cap = p.level_cap(last)
             if int(raw) > cap:
-                raise RuntimeError(
+                drv.state = None
+                drv.state_lost = (
                     f"sLSM deepest level overflow ({int(raw)} > {cap} "
                     f"live elements): increase max_levels beyond "
-                    f"{p.max_levels}")
-            drv.state = new_state
+                    f"{p.max_levels}; this engine's state went into the "
+                    f"overflowing compaction, so restore() it from its "
+                    f"durability directory")
+                raise RuntimeError(drv.state_lost)
             self._book_merge(rows_in, int(raw))
             drv.stats["compactions"] += 1
 
@@ -485,20 +495,20 @@ class MergeScheduler:
 
     # -- program warm-up ---------------------------------------------------
 
-    def warm(self) -> None:
-        """Precompile every maintenance program this engine can dispatch.
+    def programs(self) -> list:
+        """Every maintenance program this engine can dispatch, as
+        ``(jitted_fn, abstract args)`` pairs for
+        `precompile.compile_programs`.
 
         Static shapes make the set enumerable up front: each step op is
         jit-specialized on (params, levels-pytree structure, and for
         spills the static n_merge / annihilation flag), so the programs a
-        run will ever need are exactly the combinations below. Programs
-        are shape-specialized, not value-specialized — executing each
-        once on a throwaway zero state compiles the real path. Without
-        this, every first-use compile (hundreds of ms) lands inside
+        run will ever need are exactly the combinations below. Without
+        compiling them first, every first-use compile lands inside
         whichever insert chunk happens to trigger it: a stall the pacing
         budget cannot flatten, because it rides the very step dispatch
-        that was paced. One-off; results are discarded; the jit cache is
-        process-global, so same-param engines share the warmth.
+        that was paced. The jit cache is process-global, so same-param
+        engines share the warmth.
         """
         from repro.engine.tuner import ReadModePolicy, retune_filters
         base, policy = self.drv.p, self.drv.policy
@@ -517,30 +527,26 @@ class MergeScheduler:
             param_sets = [base]
             spill_sizes = policy.spill_sizes(base)
         last = base.max_levels - 1
-        outs = []
+        progs = []
         for p in param_sets:
             rn = p.Rn
-            dk = jnp.full((rn,), 0, jnp.int32)
-            dv = jnp.zeros((rn,), jnp.int32)
-            dw = jnp.ones((rn,), jnp.int32)
             for n_levels in range(p.max_levels + 1):
-                # fresh dummies per call: these ops donate their state
-                outs.append(stage_append(p, init_state(p, n_levels), dk, dv,
-                                         dw, jnp.int32(0)))
-                outs.append(seal_run(p, init_state(p, n_levels)))
+                st = state_shapes(p, n_levels)
+                progs.append((stage_append,
+                              (p, st, i32(rn), i32(rn), i32(rn), i32())))
+                progs.append((seal_run, (p, st)))
                 if len(param_sets) > 1:
-                    outs.append(retune_filters(p, init_state(p, n_levels)))
+                    progs.append((retune_filters, (p, st)))
                 if n_levels == 0:
                     continue
                 for drop in (True, False):
-                    outs.append(merge_buffer_to_level0(
-                        p, init_state(p, n_levels), drop))
+                    progs.append((merge_buffer_to_level0, (p, st, drop)))
                 # spill of level l runs after its target l+1 materializes
                 for lvl in range(min(n_levels - 1, last)):
                     for n_merge in spill_sizes:
                         for drop in (True, False):
-                            outs.append(merge_level_down(
-                                p, init_state(p, n_levels), lvl, n_merge,
-                                drop))
-            outs.append(compact_last_level(p, init_state(p, p.max_levels)))
-        jax.block_until_ready(outs)
+                            progs.append((merge_level_down,
+                                          (p, st, lvl, n_merge, drop)))
+            progs.append((compact_last_level,
+                          (p, state_shapes(p, p.max_levels))))
+        return progs

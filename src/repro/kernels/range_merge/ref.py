@@ -21,9 +21,13 @@ def range_merge_ref(keys, vals, wts, seqs, offsets, drop_annihilated: bool):
     del offsets
     q, cand = keys.shape
     idx = jnp.broadcast_to(jnp.arange(cand, dtype=jnp.int32), (q, cand))
-    k, s, w, idx = jax.lax.sort(
-        (keys.astype(jnp.int32), seqs.astype(jnp.int32),
-         wts.astype(jnp.int32), idx), num_keys=2)
+    # (key, seq) is unique per live candidate and the padding lanes are
+    # identical, so the unstable sort orders rows as a stable one would
+    # (core.runs.sort_records: fewer sort operands, faster TPU compiles)
+    k, s, idx = jax.lax.sort(
+        (keys.astype(jnp.int32), seqs.astype(jnp.int32), idx), num_keys=2,
+        is_stable=False)
+    w = jnp.take_along_axis(wts.astype(jnp.int32), idx, axis=1)
     nxt = jnp.concatenate(
         [k[:, 1:], jnp.full((k.shape[0], 1), KEY_EMPTY, k.dtype)], axis=1)
     keep = (k != KEY_EMPTY) & (k != nxt)

@@ -38,3 +38,16 @@ def test_invalid_keys_not_inserted():
     # key 6 was masked out; it may still collide, but with 64*32 bits and
     # 2 inserted keys the probability is negligible
     assert not probe[1]
+
+
+def test_sliced_build_is_bit_identical(rng, monkeypatch):
+    """A run hashed in many BUILD_CHUNK slices (the padded tail slice
+    included) gives the same filter as the same run in one slice."""
+    from repro.core import bloom as BL
+    n, words, k = 1000, 600, 7
+    ks = jnp.asarray(rng.integers(0, 2**30, n).astype(np.int32))
+    valid = jnp.asarray(rng.random(n) < 0.8)
+    whole = BL.bloom_build(ks, valid, words, k, bits=words * 32 - 40)
+    monkeypatch.setattr(BL, "BUILD_CHUNK", 96)
+    sliced = BL.bloom_build(ks, valid, words, k, bits=words * 32 - 40)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(sliced))

@@ -124,6 +124,32 @@ def test_leveling_policy_matches_oracle_and_bounds_runs():
         assert int(lv.n_runs) <= 2
 
 
+@pytest.mark.parametrize("geo,key_space", [
+    (dict(D=3, m=0.5, max_levels=2), 80),    # ceil(m*D) < D
+    (dict(D=3, m=1.0, max_levels=1), 50),    # deepest level fed by flushes
+    (dict(D=3, m=1.0, max_levels=2), 120),
+])
+@pytest.mark.parametrize("policy", [TieringPolicy, LevelingPolicy])
+def test_deepest_compaction_reads_only_occupiable_lanes(geo, key_space,
+                                                         policy):
+    """The deepest compaction merges slot 0 plus only the spill- (or
+    flush-) sized head of every other slot (compaction.compaction_rows);
+    repeated compactions under either policy stay oracle-exact."""
+    p = SLSMParams(R=4 if geo["m"] < 1 else 3, Rn=8, eps=0.05, mu=4,
+                   max_range=512, **geo)
+    t, o = SLSM(p, policy=policy()), DictOracle()
+    qs = _random_schedule(t, o, seed=5, rounds=40, key_space=key_space)
+    assert t.stats["compactions"] >= 2
+    v1, f1 = t.lookup(qs)
+    v2, f2 = o.lookup(qs)
+    np.testing.assert_array_equal(f1, f2)
+    np.testing.assert_array_equal(v1[f1], v2[f2])
+    k1, w1 = t.range(-5, key_space + 5)
+    k2, w2 = o.range(-5, key_space + 5)
+    np.testing.assert_array_equal(k1, k2)
+    np.testing.assert_array_equal(w1, w2)
+
+
 def test_leveling_policy_rejects_unsupported_geometry():
     # ceil(m*D) = 1 < max_resident: a spill could not fit the next level
     with pytest.raises(ValueError, match="LevelingPolicy"):

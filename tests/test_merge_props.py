@@ -1,6 +1,7 @@
 """HeapMerge hypothesis sweep: sort-based, rank-based, and the Pallas
-tournament agree on arbitrary run sets — module degrades to a skip when
-hypothesis is not installed."""
+tournament agree with `oracle_merge` on arbitrary weighted run sets
+(keys, vals, weights, seqs — DESIGN.md §13) — module degrades to a skip
+when hypothesis is not installed."""
 import pytest
 
 pytest.importorskip("hypothesis")
@@ -20,12 +21,14 @@ from test_merge import make_runs, oracle_merge
        seed=st.integers(0, 10**6), drop=st.booleans())
 def test_merge_paths_agree(k, cap, seed, drop):
     rng = np.random.default_rng(seed)
-    K, V, S = make_runs(rng, k, cap)
-    expect = oracle_merge(np.asarray(K), np.asarray(V), np.asarray(S), drop)
+    K, V, W, S = make_runs(rng, k, cap)
+    expect = oracle_merge(np.asarray(K), np.asarray(V), np.asarray(W),
+                          np.asarray(S), drop)
 
     for fn in (RU.merge_runs, RU.merge_kway_ranked, heap_merge_op):
-        mk, mv, ms, cnt = fn(K, V, S, drop)
+        mk, mv, mw, ms, cnt = fn(K, V, W, S, drop)
         got = list(zip(np.asarray(mk)[:int(cnt)].tolist(),
                        np.asarray(mv)[:int(cnt)].tolist(),
+                       np.asarray(mw)[:int(cnt)].tolist(),
                        np.asarray(ms)[:int(cnt)].tolist()))
         assert got == expect, fn.__name__

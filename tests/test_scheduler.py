@@ -63,8 +63,9 @@ def test_step_cost_matches_level_geometry():
     p = _params(0)
     assert step_cost(FLUSH, -1, p) == p.runs_merged * p.Rn
     assert step_cost(SPILL, 0, p) == p.disk_runs_merged * p.level_cap(0)
+    # slot 0 in D pieces plus D-1 spill-sized slots (m=1)
     assert step_cost(COMPACT, p.max_levels - 1, p) == (
-        p.D * p.level_cap(p.max_levels - 1))
+        (2 * p.D - 1) * p.D * p.level_cap(p.max_levels - 2))
 
 
 def test_negative_merge_budget_rejected():

@@ -81,6 +81,10 @@ def test_overflow_raises():
     with pytest.raises(RuntimeError, match="max_levels"):
         t.insert(np.arange(4000, dtype=np.int32),
                  np.arange(4000, dtype=np.int32))
+    # the overflowing compaction consumed the (donated) state: the engine
+    # stays unusable, with the same declared error, until restored
+    with pytest.raises(RuntimeError, match="restore"):
+        t.lookup(np.arange(4, dtype=np.int32))
 
 
 def test_r_tradeoff_more_runs_fewer_merges():

@@ -24,6 +24,10 @@ Parent/child harness in one file:
 Exit 0 == recovery is crash-exact. Any mismatch, missing telemetry, or
 unreadable-but-nonempty WAL is a hard failure.
 
+A CPU crash gate: parent and child both pin JAX to the CPU (a chip
+serves one process at a time). The chip's end-to-end check is
+`chip_smoke.py`.
+
 Usage:
     python tools/recovery_smoke.py [--kill-after-bytes N] [--dir DIR]
 """
@@ -39,6 +43,11 @@ import time
 
 import numpy as np
 
+# CPU_ONLY: a crash gate needs two JAX processes (one to kill, one to
+# check), and a TPU chip serves one process at a time: a parent
+# holding the chip would leave the child silently on the CPU. So
+# both sides pin JAX to the CPU, before it is imported.
+os.environ["JAX_PLATFORMS"] = "cpu"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "src"))
 
@@ -108,7 +117,7 @@ def run_child(durdir: str) -> None:
 def run_parent(durdir: str, kill_after_bytes: int) -> int:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"   # see CPU_ONLY above
     child = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "--child",
          "--dir", durdir], env=env)

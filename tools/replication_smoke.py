@@ -37,6 +37,10 @@ raise, ship inert), re-bootstraps from the new leader as a follower,
 and must serve reads bitwise-equal to the new leader. Exit 0 == all of
 automatic promotion, fencing, and the rejoined replica's answers hold.
 
+Both modes are CPU crash gates: parent and child pin JAX to the CPU (a
+chip serves one process at a time). The chip's end-to-end check is
+`chip_smoke.py`.
+
 Usage:
     python tools/replication_smoke.py [--kill-after-records N]
     python tools/replication_smoke.py --partition [--lease-s S]
@@ -54,6 +58,11 @@ import time
 
 import numpy as np
 
+# CPU_ONLY: a crash gate needs two JAX processes (one to kill, one to
+# check), and a TPU chip serves one process at a time: a parent
+# holding the chip would leave the child silently on the CPU. So
+# both sides pin JAX to the CPU, before it is imported.
+os.environ["JAX_PLATFORMS"] = "cpu"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "src"))
 
@@ -207,7 +216,7 @@ def run_parent_partition(d: str, kill_after_records: int,
     lis = R.SocketListener()
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"   # see CPU_ONLY above
     child = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "--child",
          "--partition", "--dir", ldir, "--fol-dir", fdir,
@@ -320,7 +329,7 @@ def run_parent(leader_dir: str, fol_dir: str,
     lis = R.SocketListener()
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"   # see CPU_ONLY above
     child = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "--child",
          "--dir", leader_dir, "--fol-dir", fol_dir,
