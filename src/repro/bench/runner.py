@@ -689,9 +689,13 @@ def run_scenario(sc: Scenario, out_dir: str | Path,
             "batched_speedup": (None if batched is None else
                                 batched["ops_per_s"]
                                 / max(per_query["ops_per_s"], 1e-12)),
-            "zset": {k: int(tree.stats[k]) for k in
-                     ("rows_merged_in", "rows_merged_out",
-                      "rows_annihilated", "ghost_payload_bytes_skipped")},
+            "zset": dict({k: int(tree.stats[k]) for k in
+                          ("rows_merged_in", "rows_merged_out",
+                           "rows_annihilated")},
+                         # payload bytes the Ghost gather skipped: 4 a
+                         # row kept out of a merge's output
+                         ghost_payload_bytes_skipped=4 * int(
+                             tree.stats["rows_annihilated"])),
             "maintenance": {k: int(tree.stats[k]) for k in
                             ("seals", "flushes", "spills", "compactions",
                              "backlog_peak", "retunes")},

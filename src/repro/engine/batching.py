@@ -16,7 +16,9 @@ module is the single home for that policy:
   * the pad helpers (`pad_to`, `pad_pow2`) that realize a bucket as a
     KEY_EMPTY-padded lane array;
   * `range_many_host`  — the shared pad/dispatch/trim driver for the
-    batched range entry points of both engines.
+    batched range entry points of both engines;
+  * `host_read`        — the one way the driver and its scheduler copy a
+    device value to the host, counted in ``stats["host_syncs"]``.
 
 Until PR 6 these lived as underscore-privates in `engine.py` and were
 imported across modules (`sharded.py`) — promoting them makes the grid
@@ -24,6 +26,7 @@ a public contract the serving layer can warm against.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -96,12 +99,22 @@ def tape_bucket(n: int) -> int:
     return bucket_pow2(n)
 
 
-def range_many_host(dispatch, max_range: int, ranges):
+def host_read(x, stats=None) -> np.ndarray:
+    """``np.asarray(x)``: one blocking device-to-host read (it waits for
+    the program that computes `x`), counted in ``stats["host_syncs"]``
+    when a stats counter is given."""
+    if stats is not None:
+        stats["host_syncs"] += 1
+    return np.asarray(x)
+
+
+def range_many_host(dispatch, max_range: int, ranges, stats=None):
     """Shared `range_many` driver for both engines: pad the scan list to
     the `RANGE_BUCKETS` grid, run the engine's jitted batched program
     ``dispatch(los, his, n_valid)``, trim back to the Q requested rows.
     One implementation so the bucket grid, padding dtype, and empty-batch
-    contract cannot diverge between drivers."""
+    contract cannot diverge between drivers. The four result copies
+    (`host_read`, counted in `stats`) run in the ``slsm.fetch`` span."""
     r = np.asarray(ranges, np.int32).reshape(-1, 2)
     q = r.shape[0]
     if q == 0:
@@ -114,5 +127,6 @@ def range_many_host(dispatch, max_range: int, ranges):
     los[:q], his[:q] = r[:, 0], r[:, 1]
     k, v, c, trunc = dispatch(jnp.asarray(los), jnp.asarray(his),
                               jnp.int32(q))
-    return (np.asarray(k)[:q], np.asarray(v)[:q],
-            np.asarray(c)[:q], np.asarray(trunc)[:q])
+    with jax.profiler.TraceAnnotation("slsm.fetch"):
+        return (host_read(k, stats)[:q], host_read(v, stats)[:q],
+                host_read(c, stats)[:q], host_read(trunc, stats)[:q])

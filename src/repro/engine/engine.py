@@ -26,8 +26,8 @@ from repro.engine import wal as WAL
 from repro.engine.backend import get_backend
 from repro.engine.batching import (ADAPTIVE_BUCKETS, RANGE_BUCKETS,
                                    TAPE_BUCKETS, adaptive_bucket,
-                                   bucket_pow2, pad_to, range_bucket,
-                                   range_many_host)
+                                   bucket_pow2, host_read, pad_to,
+                                   range_bucket, range_many_host)
 from repro.engine.compaction import (CompactionPolicy, LevelingPolicy,
                                      TieringPolicy)
 from repro.engine.memtable import init_state, stage_append
@@ -106,13 +106,16 @@ class SLSM:
         # maintenance counters (the bench runner's merge-count trajectory);
         # backlog_peak = most pending merge steps ever observed at a chunk
         # boundary (0 in synchronous mode only if no step was ever
-        # deferred); reads/writes feed the tuner's workload-mix signal
+        # deferred); reads/writes feed the tuner's workload-mix signal;
+        # host_syncs = blocking device-to-host reads of the driver and
+        # its scheduler (batching.host_read), chunks_staged =
+        # stage_append dispatches
         self.stats = collections.Counter(seals=0, flushes=0, spills=0,
                                          compactions=0, backlog_peak=0,
                                          retunes=0, reads=0, writes=0,
                                          rows_merged_in=0, rows_merged_out=0,
-                                         rows_annihilated=0,
-                                         ghost_payload_bytes_skipped=0)
+                                         rows_annihilated=0, host_syncs=0,
+                                         chunks_staged=0)
         # durability surface (DESIGN.md §12): None (default) = volatile
         # engine, a path or wal.Durability = WAL every write op +
         # snapshot on demand; _replaying suppresses re-logging while
@@ -177,30 +180,37 @@ class SLSM:
         weight -1 records). With durability on, the whole op is logged as
         one WAL record before any device state changes and
         group-committed before returning (one fsync per driver call, not
-        per chunk — DESIGN.md §12)."""
-        if len(keys) > 0:
-            self._guard_writes()
-        log = (self.durability is not None and not self._replaying
-               and len(keys) > 0)
-        if log:
-            self.durability.log_write(keys, vals, wts)
-        self.stats["writes"] += len(keys)
-        self.tuner.note_writes(len(keys))
-        rn = self.p.Rn
-        for off in range(0, len(keys), rn):
-            ck, cv = keys[off:off + rn], vals[off:off + rn]
-            cw = wts[off:off + rn]
-            n = len(ck)
-            if n < rn:
-                ck = np.pad(ck, (0, rn - n), constant_values=KEY_EMPTY)
-                cv = np.pad(cv, (0, rn - n))
-                cw = np.pad(cw, (0, rn - n))
-            self.state = stage_append(self.p_active, self.state,
-                                      jnp.asarray(ck), jnp.asarray(cv),
-                                      jnp.asarray(cw), jnp.int32(n))
-            self.scheduler.on_chunk()
-        if log:
-            self.durability.sync()
+        per chunk — DESIGN.md §12).
+
+        Spans: ``slsm.write`` around the call, ``slsm.stage`` around each
+        chunk's padding, puts and `stage_append` dispatch, and the
+        scheduler's ``slsm.schedule`` after it."""
+        with jax.profiler.TraceAnnotation("slsm.write"):
+            if len(keys) > 0:
+                self._guard_writes()
+            log = (self.durability is not None and not self._replaying
+                   and len(keys) > 0)
+            if log:
+                self.durability.log_write(keys, vals, wts)
+            self.stats["writes"] += len(keys)
+            self.tuner.note_writes(len(keys))
+            rn = self.p.Rn
+            for off in range(0, len(keys), rn):
+                with jax.profiler.TraceAnnotation("slsm.stage"):
+                    ck, cv = keys[off:off + rn], vals[off:off + rn]
+                    cw = wts[off:off + rn]
+                    n = len(ck)
+                    if n < rn:
+                        ck = np.pad(ck, (0, rn - n), constant_values=KEY_EMPTY)
+                        cv = np.pad(cv, (0, rn - n))
+                        cw = np.pad(cw, (0, rn - n))
+                    self.state = stage_append(self.p_active, self.state,
+                                              jnp.asarray(ck), jnp.asarray(cv),
+                                              jnp.asarray(cw), jnp.int32(n))
+                    self.stats["chunks_staged"] += 1
+                self.scheduler.on_chunk()
+            if log:
+                self.durability.sync()
 
     def delete(self, keys) -> None:
         """Deletes are weight -1 records (paper 2.8 tombstones, recast as
@@ -291,26 +301,31 @@ class SLSM:
         qs = jnp.asarray(qs_np)
         vals, found = lookup_batch(self.p_active, self.state, qs, sparse,
                                    self.tuner.enabled)
-        return np.asarray(vals), np.asarray(found)
+        return host_read(vals, self.stats), host_read(found, self.stats)
 
     def lookup_many(self, keys, sparse: bool = False):
         """Batched multi-key fast path: all Q lookups in ONE device
         dispatch — a single fused Bloom-probe + fence-search pass per
         structure (paper 2.3/2.4) instead of one dispatch per query.
         Queries are padded to a power-of-two bucket so arbitrary Q reuses
-        O(log Q) compiled programs. Same results as `lookup`."""
-        qs = np.asarray(keys, np.int32).reshape(-1)
-        reject_reserved(qs, op="lookup_many")
-        if qs.size == 0:
-            return np.zeros(0, np.int32), np.zeros(0, bool)
-        self._on_reads(qs)
-        width = (adaptive_bucket(qs.size) if self.tuner.enabled
-                 else bucket_pow2(qs.size))
-        vals, found = lookup_many(self.p_active, self.state,
-                                  jnp.asarray(pad_to(qs, width)),
-                                  jnp.int32(qs.size), sparse,
-                                  self.tuner.enabled)
-        return np.asarray(vals)[:qs.size], np.asarray(found)[:qs.size]
+        O(log Q) compiled programs. Same results as `lookup`. Runs in a
+        ``slsm.lookup_many`` span, the answers' copy to the host in
+        ``slsm.fetch``."""
+        with jax.profiler.TraceAnnotation("slsm.lookup_many"):
+            qs = np.asarray(keys, np.int32).reshape(-1)
+            reject_reserved(qs, op="lookup_many")
+            if qs.size == 0:
+                return np.zeros(0, np.int32), np.zeros(0, bool)
+            self._on_reads(qs)
+            width = (adaptive_bucket(qs.size) if self.tuner.enabled
+                     else bucket_pow2(qs.size))
+            vals, found = lookup_many(self.p_active, self.state,
+                                      jnp.asarray(pad_to(qs, width)),
+                                      jnp.int32(qs.size), sparse,
+                                      self.tuner.enabled)
+            with jax.profiler.TraceAnnotation("slsm.fetch"):
+                return (host_read(vals, self.stats)[:qs.size],
+                        host_read(found, self.stats)[:qs.size])
 
     def range_device(self, lo: int, hi: int):
         """Device-resident range query [lo, hi) (paper 2.9): one jitted
@@ -333,9 +348,10 @@ class SLSM:
         Convenience trim of `range_device` (this is where the one host
         sync happens)."""
         k, v, c, trunc = self.range_device(lo, hi)
-        c = int(c)
-        out = np.asarray(k)[:c], np.asarray(v)[:c]
-        return out + (bool(trunc),) if return_truncated else out
+        c = int(host_read(c, self.stats))
+        out = host_read(k, self.stats)[:c], host_read(v, self.stats)[:c]
+        return (out + (bool(host_read(trunc, self.stats)),)
+                if return_truncated else out)
 
     def range_many(self, ranges):
         """Batched multi-scan fast path: all Q scans ``[(lo, hi), ...)``
@@ -349,11 +365,12 @@ class SLSM:
         Returns ``(keys (Q, max_range), vals, counts (Q,),
         truncated (Q,))`` as numpy arrays; row i holds ``counts[i]``
         key-sorted live pairs for window i (see `range` for the
-        truncated-flag contract)."""
-        return range_many_host(
-            lambda los, his, n: range_many(self.p_active, self.state,
-                                           los, his, n),
-            self.p.max_range, ranges)
+        truncated-flag contract). Runs in a ``slsm.range_many`` span."""
+        with jax.profiler.TraceAnnotation("slsm.range_many"):
+            return range_many_host(
+                lambda los, his, n: range_many(self.p_active, self.state,
+                                               los, his, n),
+                self.p.max_range, ranges, self.stats)
 
     def aggregate_many(self, ranges):
         """Batched windowed aggregates: ``count(lo, hi)`` and
@@ -379,7 +396,8 @@ class SLSM:
         c, s, t = aggregate_many(self.p_active, self.state,
                                  jnp.asarray(los), jnp.asarray(his),
                                  jnp.int32(q))
-        return np.asarray(c)[:q], np.asarray(s)[:q], np.asarray(t)[:q]
+        return (host_read(c, self.stats)[:q], host_read(s, self.stats)[:q],
+                host_read(t, self.stats)[:q])
 
     def count(self, lo: int, hi: int) -> int:
         """Live-key count over [lo, hi) (exact; one-window
@@ -402,7 +420,8 @@ class SLSM:
         down to ``run_count % runs_merged_eff``. Serving layers split
         windows that exceed this into multiple tapes."""
         p = self.p_active
-        rc, sc = int(self.state.run_count), int(self.state.stage_count)
+        rc = int(host_read(self.state.run_count, self.stats))
+        sc = int(host_read(self.state.stage_count, self.stats))
         # mirror ensure_stage_space(): pre-existing full stage seals first
         while sc >= p.Rn:
             if rc >= p.R:
@@ -507,8 +526,9 @@ class SLSM:
                 seg_idx.append(i)
                 work.pop(0)
             assert seg, "tape segmentation made no progress"
-            seals = TP.tape_seal_bound(self.p_active,
-                                       int(self.state.stage_count), seg)
+            seals = TP.tape_seal_bound(
+                self.p_active,
+                int(host_read(self.state.stage_count, self.stats)), seg)
             if seals:
                 self.scheduler.reserve_run_slots(seals)
             ops, keys, vals, wts, nv = TP.build_tape(self.p_active, seg)

@@ -61,9 +61,11 @@ from __future__ import annotations
 
 from typing import List, NamedTuple, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.params import SLSMParams
+from repro.engine.batching import host_read
 from repro.engine.compaction import (CompactionPolicy, compact_last_level,
                                      compaction_rows, merge_buffer_to_level0,
                                      merge_level_down)
@@ -82,10 +84,13 @@ class Occupancy(NamedTuple):
     level_runs: Tuple[int, ...]   # n_runs per *materialized* level
 
 
-def occupancy_of(state) -> Occupancy:
-    """Snapshot a (single-tree) state pytree's occupancy counters."""
-    return Occupancy(int(state.stage_count), int(state.run_count),
-                     tuple(int(lv.n_runs) for lv in state.levels))
+def occupancy_of(state, stats=None) -> Occupancy:
+    """Snapshot a (single-tree) state pytree's occupancy counters: 2 +
+    len(levels) device reads (`host_read`, counted in `stats`)."""
+    return Occupancy(int(host_read(state.stage_count, stats)),
+                     int(host_read(state.run_count, stats)),
+                     tuple(int(host_read(lv.n_runs, stats))
+                           for lv in state.levels))
 
 
 def step_order(p: SLSMParams) -> List[Tuple[str, int]]:
@@ -191,13 +196,13 @@ def backlog_cost(steps: Sequence[MergeStep]) -> int:
     return sum(s.cost for s in steps)
 
 
-def drop_annihilated_into(state, target_level: int) -> bool:
+def drop_annihilated_into(state, target_level: int, stats=None) -> bool:
     """Deletes commit (negative-weight records annihilate) when the merge
     output becomes the deepest data (paper 2.5/2.8) — evaluated at
     step-run time, exactly as the synchronous cascade evaluated it at
     recursion time."""
     for lv in state.levels[target_level:]:
-        if int(lv.n_runs) > 0:
+        if int(host_read(lv.n_runs, stats)) > 0:
             return False
     return True
 
@@ -251,65 +256,72 @@ class MergeScheduler:
         st["rows_merged_in"] += rows_in
         st["rows_merged_out"] += rows_out
         st["rows_annihilated"] += rows_in - rows_out
-        st["ghost_payload_bytes_skipped"] += 4 * (rows_in - rows_out)
+
+    def _read(self, x) -> int:
+        """One device scalar on the host (`host_read`, counted in the
+        driver's ``host_syncs``)."""
+        return int(host_read(x, self.drv.stats))
 
     def run_step(self, step: MergeStep) -> None:
         """Execute one step as a single jitted device dispatch (or, for
         RETUNE, the driver's filter-rebuild + active-params swap) and
         bump the matching stats counter. The one place steps become
         state transitions — pacing, forcing, and draining all funnel
-        through here."""
-        drv, p = self.drv, self.p
-        if step.kind == RETUNE:
-            drv.apply_retune()
-            drv.stats["retunes"] += 1
-        elif step.kind == SEAL:
-            drv.state = seal_run(p, drv.state)
-            drv.stats["seals"] += 1
-        elif step.kind == FLUSH:
-            self._materialize(0)
-            mr = p.runs_merged_eff
-            rows_in = int(jnp.sum(drv.state.buf_counts[:mr]))
-            slot = int(drv.state.levels[0].n_runs)
-            drv.state = merge_buffer_to_level0(
-                p, drv.state, drop_annihilated_into(drv.state, 0))
-            self._book_merge(rows_in,
-                             int(drv.state.levels[0].counts[slot]))
-            drv.stats["flushes"] += 1
-        elif step.kind == SPILL:
-            self._materialize(step.level + 1)
-            n_merge = self.policy.runs_to_spill(
-                p, int(drv.state.levels[step.level].n_runs))
-            rows_in = int(jnp.sum(
-                drv.state.levels[step.level].counts[:n_merge]))
-            slot = int(drv.state.levels[step.level + 1].n_runs)
-            drv.state = merge_level_down(
-                p, drv.state, step.level, n_merge,
-                drop_annihilated_into(drv.state, step.level + 1))
-            self._book_merge(
-                rows_in,
-                int(drv.state.levels[step.level + 1].counts[slot]))
-            drv.stats["spills"] += 1
-        else:   # COMPACT
-            last = p.max_levels - 1
-            rows_in = int(jnp.sum(drv.state.levels[last].counts))
-            # the compaction donates the state (at deployment geometry a
-            # second copy of it does not fit beside the merge), so an
-            # overflow leaves nothing to roll back to: the engine drops
-            # its state and every later call raises the same error
-            drv.state, raw = compact_last_level(p, drv.state)
-            cap = p.level_cap(last)
-            if int(raw) > cap:
-                drv.state = None
-                drv.state_lost = (
-                    f"sLSM deepest level overflow ({int(raw)} > {cap} "
-                    f"live elements): increase max_levels beyond "
-                    f"{p.max_levels}; this engine's state went into the "
-                    f"overflowing compaction, so restore() it from its "
-                    f"durability directory")
-                raise RuntimeError(drv.state_lost)
-            self._book_merge(rows_in, int(raw))
-            drv.stats["compactions"] += 1
+        through here; each runs in a ``slsm.step.<kind>`` span."""
+        with jax.profiler.TraceAnnotation(f"slsm.step.{step.kind}"):
+            drv, p = self.drv, self.p
+            if step.kind == RETUNE:
+                drv.apply_retune()
+                drv.stats["retunes"] += 1
+            elif step.kind == SEAL:
+                drv.state = seal_run(p, drv.state)
+                drv.stats["seals"] += 1
+            elif step.kind == FLUSH:
+                self._materialize(0)
+                mr = p.runs_merged_eff
+                rows_in = self._read(jnp.sum(drv.state.buf_counts[:mr]))
+                slot = self._read(drv.state.levels[0].n_runs)
+                drv.state = merge_buffer_to_level0(
+                    p, drv.state,
+                    drop_annihilated_into(drv.state, 0, drv.stats))
+                self._book_merge(rows_in,
+                                 self._read(drv.state.levels[0].counts[slot]))
+                drv.stats["flushes"] += 1
+            elif step.kind == SPILL:
+                self._materialize(step.level + 1)
+                n_merge = self.policy.runs_to_spill(
+                    p, self._read(drv.state.levels[step.level].n_runs))
+                rows_in = self._read(jnp.sum(
+                    drv.state.levels[step.level].counts[:n_merge]))
+                slot = self._read(drv.state.levels[step.level + 1].n_runs)
+                drv.state = merge_level_down(
+                    p, drv.state, step.level, n_merge,
+                    drop_annihilated_into(drv.state, step.level + 1,
+                                          drv.stats))
+                self._book_merge(
+                    rows_in,
+                    self._read(drv.state.levels[step.level + 1].counts[slot]))
+                drv.stats["spills"] += 1
+            else:   # COMPACT
+                last = p.max_levels - 1
+                rows_in = self._read(jnp.sum(drv.state.levels[last].counts))
+                # the compaction donates the state (at deployment geometry a
+                # second copy of it does not fit beside the merge), so an
+                # overflow leaves nothing to roll back to: the engine drops
+                # its state and every later call raises the same error
+                drv.state, raw = compact_last_level(p, drv.state)
+                cap = p.level_cap(last)
+                if self._read(raw) > cap:
+                    drv.state = None
+                    drv.state_lost = (
+                        f"sLSM deepest level overflow ({self._read(raw)} > "
+                        f"{cap} live elements): increase max_levels beyond "
+                        f"{p.max_levels}; this engine's state went into the "
+                        f"overflowing compaction, so restore() it from its "
+                        f"durability directory")
+                    raise RuntimeError(drv.state_lost)
+                self._book_merge(rows_in, self._read(raw))
+                drv.stats["compactions"] += 1
 
     # -- forced chain (== the legacy synchronous cascade) ------------------
 
@@ -326,7 +338,7 @@ class MergeScheduler:
             self._materialize(level)
             return
         if not self.policy.needs_spill(
-                p, int(drv.state.levels[level].n_runs), level):
+                p, self._read(drv.state.levels[level].n_runs), level):
             return
         if level == p.max_levels - 1:
             self.run_step(MergeStep(COMPACT, level,
@@ -341,7 +353,7 @@ class MergeScheduler:
         """Deepest pending step that is ready under the live occupancy
         (None if the backlog is empty or wholly blocked)."""
         p, policy = self.p, self.policy
-        occ = occupancy_of(self.drv.state)
+        occ = occupancy_of(self.drv.state, self.drv.stats)
         for step in pending_steps(p, policy, occ, self._retune_pending()):
             if step.ready(occ, p, policy):
                 return step
@@ -361,40 +373,45 @@ class MergeScheduler:
         for out of the same voluntary budget as any merge. In
         synchronous mode (merge_budget == 0) the voluntary pass is
         empty, so a pending retune — like every other piece of
-        maintenance in that mode — runs inline, immediately."""
-        drv, p = self.drv, self.p
-        tuner = getattr(drv, "tuner", None)
-        if tuner is not None:
-            tuner.decide()
-            if tuner.take_probe_sample():
-                sampler = getattr(drv, "sample_probe_stats", None)
-                if sampler is not None:
-                    sampler()
-        backlog = pending_steps(p, self.policy, occupancy_of(drv.state),
-                                self._retune_pending())
-        drv.stats["backlog_peak"] = max(drv.stats["backlog_peak"],
-                                        len(backlog))
-        budget = p.merge_budget
-        # read-mode catch-up: while the read-optimized allocation is (or
-        # is about to be) active, writes are a trickle and every one of
-        # them is a chance to fold structure the read path then skips —
-        # so the voluntary pass runs to quiescence instead of rationing.
-        # Write-phase pacing (the whole point of merge_budget) is
-        # untouched: catch-up applies only in/INTO read mode — a pending
-        # switch to any other allocation stays budget-paced.
-        catch_up = (budget > 0 and tuner is not None and tuner.enabled
-                    and (tuner.active == "read"
-                         or (tuner.pending and tuner.target == "read")))
-        while budget > 0 or catch_up:
-            step = self._next_ready()
-            if step is None:
-                break
-            self.run_step(step)
-            budget -= 1
-        if p.merge_budget == 0 and self._retune_pending():
-            self.run_step(MergeStep(RETUNE, -1, step_cost(RETUNE, -1, p)))
-        # forced: the staging buffer must fit the next Rn-chunk
-        self.ensure_stage_space()
+        maintenance in that mode — runs inline, immediately.
+
+        Runs in a ``slsm.schedule`` span: the occupancy reads, the
+        planning and the steps (each in its own ``slsm.step.<kind>``)."""
+        with jax.profiler.TraceAnnotation("slsm.schedule"):
+            drv, p = self.drv, self.p
+            tuner = getattr(drv, "tuner", None)
+            if tuner is not None:
+                tuner.decide()
+                if tuner.take_probe_sample():
+                    sampler = getattr(drv, "sample_probe_stats", None)
+                    if sampler is not None:
+                        sampler()
+            backlog = pending_steps(p, self.policy,
+                                    occupancy_of(drv.state, drv.stats),
+                                    self._retune_pending())
+            drv.stats["backlog_peak"] = max(drv.stats["backlog_peak"],
+                                            len(backlog))
+            budget = p.merge_budget
+            # read-mode catch-up: while the read-optimized allocation is (or
+            # is about to be) active, writes are a trickle and every one of
+            # them is a chance to fold structure the read path then skips —
+            # so the voluntary pass runs to quiescence instead of rationing.
+            # Write-phase pacing (the whole point of merge_budget) is
+            # untouched: catch-up applies only in/INTO read mode — a pending
+            # switch to any other allocation stays budget-paced.
+            catch_up = (budget > 0 and tuner is not None and tuner.enabled
+                        and (tuner.active == "read"
+                             or (tuner.pending and tuner.target == "read")))
+            while budget > 0 or catch_up:
+                step = self._next_ready()
+                if step is None:
+                    break
+                self.run_step(step)
+                budget -= 1
+            if p.merge_budget == 0 and self._retune_pending():
+                self.run_step(MergeStep(RETUNE, -1, step_cost(RETUNE, -1, p)))
+            # forced: the staging buffer must fit the next Rn-chunk
+            self.ensure_stage_space()
 
     def ensure_stage_space(self) -> None:
         """Forced chain: seal (flushing/cascading first when the buffer
@@ -404,8 +421,8 @@ class MergeScheduler:
         forced tail, callable standalone (the serving layer's headroom
         pass runs it between tapes)."""
         drv, p = self.drv, self.p
-        while int(drv.state.stage_count) >= p.Rn:
-            if int(drv.state.run_count) >= p.R:
+        while self._read(drv.state.stage_count) >= p.Rn:
+            if self._read(drv.state.run_count) >= p.R:
                 self.force_space(0)
                 self.run_step(MergeStep(FLUSH, -1, step_cost(FLUSH, -1, p)))
             self.run_step(MergeStep(SEAL, -1, step_cost(SEAL, -1, p)))
@@ -422,12 +439,12 @@ class MergeScheduler:
         ``R - that`` (the tape carries too many write keys — split it;
         `SLSM.tape_write_capacity` is the matching key budget)."""
         p = self.p
-        floor = int(self.drv.state.run_count) % p.runs_merged_eff
+        floor = self._read(self.drv.state.run_count) % p.runs_merged_eff
         if n > p.R - floor:
             raise ValueError(
                 f"cannot reserve {n} run slots: only {p.R - floor} "
                 f"reachable (R={p.R}, {floor} unflushable resident runs)")
-        while p.R - int(self.drv.state.run_count) < n:
+        while p.R - self._read(self.drv.state.run_count) < n:
             self.force_space(0)
             self.run_step(MergeStep(FLUSH, -1, step_cost(FLUSH, -1, p)))
 
@@ -476,7 +493,7 @@ class MergeScheduler:
         drv = self.drv
         while True:
             backlog = pending_steps(self.p, self.policy,
-                                    occupancy_of(drv.state),
+                                    occupancy_of(drv.state, drv.stats),
                                     self._retune_pending())
             if not backlog:
                 return
@@ -490,7 +507,7 @@ class MergeScheduler:
     def backlog(self) -> List[MergeStep]:
         """Current pending steps (introspection/telemetry)."""
         return pending_steps(self.p, self.policy,
-                             occupancy_of(self.drv.state),
+                             occupancy_of(self.drv.state, self.drv.stats),
                              self._retune_pending())
 
     # -- program warm-up ---------------------------------------------------

@@ -301,8 +301,7 @@ class ShardedSLSM:
                                          compactions=0, backlog_peak=0,
                                          retunes=0, reads=0, writes=0,
                                          rows_merged_in=0, rows_merged_out=0,
-                                         rows_annihilated=0,
-                                         ghost_payload_bytes_skipped=0)
+                                         rows_annihilated=0)
         # durability surface (DESIGN.md §12): write ops are logged at the
         # driver boundary BEFORE shard routing, so single-tree and
         # sharded engines fed the same stream produce byte-identical
@@ -413,7 +412,6 @@ class ShardedSLSM:
         st["rows_merged_in"] += rows_in
         st["rows_merged_out"] += rows_out
         st["rows_annihilated"] += rows_in - rows_out
-        st["ghost_payload_bytes_skipped"] += 4 * (rows_in - rows_out)
 
     def _apply_step(self, kind: str, level: int, mask: np.ndarray) -> None:
         """Run one step kind for every masked shard in a single vmapped

@@ -688,7 +688,8 @@ class WalWriter:
         self._buf.clear()
         self._f.flush()
         if fsync:
-            os.fsync(self._f.fileno())
+            with jax.profiler.TraceAnnotation("wal.fsync"):
+                os.fsync(self._f.fileno())
         self.syncs += 1
 
     def close(self) -> None:
@@ -1003,8 +1004,11 @@ class Durability:
     def log_write(self, keys, vals, wts) -> int:
         """Buffer one driver-boundary weighted write chunk; returns its
         seqno. Durable only after the next `sync` (the driver calls it
-        before any result of the op can reach a client)."""
-        return self.writer.append(REC_WRITE2, encode_write(keys, vals, wts))
+        before any result of the op can reach a client). Runs in a
+        ``wal.append`` span."""
+        with jax.profiler.TraceAnnotation("wal.append"):
+            return self.writer.append(REC_WRITE2,
+                                      encode_write(keys, vals, wts))
 
     def append_frame(self, frame: bytes) -> int:
         """Buffer one leader-framed record verbatim (the replication
@@ -1023,9 +1027,11 @@ class Durability:
     def sync(self) -> None:
         """Group commit: flush every buffered record in one write (+ one
         fsync unless configured off), then seal the active file into a
-        segment if it outgrew ``segment_bytes``."""
-        self.writer.sync(fsync=self.fsync)
-        self._maybe_roll()
+        segment if it outgrew ``segment_bytes``. Runs in a ``wal.commit``
+        span, the fsync alone in ``wal.fsync``."""
+        with jax.profiler.TraceAnnotation("wal.commit"):
+            self.writer.sync(fsync=self.fsync)
+            self._maybe_roll()
 
     def _maybe_roll(self) -> None:
         """Seal the active ``wal.log`` into ``wal_<first_seqno>.log``
