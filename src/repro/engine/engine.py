@@ -35,7 +35,7 @@ from repro.engine.precompile import compile_programs, i32, state_shapes
 from repro.engine.read_path import (aggregate_many, level_probe_stats,
                                     lookup_batch, lookup_many, range_many,
                                     range_query)
-from repro.engine.scheduler import MergeScheduler
+from repro.engine.scheduler import MergeScheduler, Occupancy
 from repro.engine.tuner import READ, ReadModePolicy, Tuner, retune_filters
 
 # fixed width of the tuner's sampled probe-telemetry dispatch: one shape
@@ -103,19 +103,24 @@ class SLSM:
         self.tuner = Tuner(self)
         self._read_policy = ReadModePolicy()
         self.scheduler = MergeScheduler(self)
+        # a fresh state: nothing staged, no memory run, no level
+        self.scheduler.track(Occupancy(0, 0, ()))
         # maintenance counters (the bench runner's merge-count trajectory);
         # backlog_peak = most pending merge steps ever observed at a chunk
         # boundary (0 in synchronous mode only if no step was ever
         # deferred); reads/writes feed the tuner's workload-mix signal;
         # host_syncs = blocking device-to-host reads of the driver and
         # its scheduler (batching.host_read), chunks_staged =
-        # stage_append dispatches
+        # stage_append dispatches, occupancy_resyncs = rebuilds of the
+        # scheduler's occupancy mirror from the device (after a state the
+        # scheduler did not install: tape, restore, replayed retune)
         self.stats = collections.Counter(seals=0, flushes=0, spills=0,
                                          compactions=0, backlog_peak=0,
                                          retunes=0, reads=0, writes=0,
                                          rows_merged_in=0, rows_merged_out=0,
                                          rows_annihilated=0, host_syncs=0,
-                                         chunks_staged=0)
+                                         chunks_staged=0,
+                                         occupancy_resyncs=0)
         # durability surface (DESIGN.md §12): None (default) = volatile
         # engine, a path or wal.Durability = WAL every write op +
         # snapshot on demand; _replaying suppresses re-logging while
@@ -204,9 +209,9 @@ class SLSM:
                         ck = np.pad(ck, (0, rn - n), constant_values=KEY_EMPTY)
                         cv = np.pad(cv, (0, rn - n))
                         cw = np.pad(cw, (0, rn - n))
-                    self.state = stage_append(self.p_active, self.state,
-                                              jnp.asarray(ck), jnp.asarray(cv),
-                                              jnp.asarray(cw), jnp.int32(n))
+                    self.scheduler.staged(stage_append(
+                        self.p_active, self.state, jnp.asarray(ck),
+                        jnp.asarray(cv), jnp.asarray(cw), jnp.int32(n)))
                     self.stats["chunks_staged"] += 1
                 self.scheduler.on_chunk()
             if log:
@@ -420,8 +425,8 @@ class SLSM:
         down to ``run_count % runs_merged_eff``. Serving layers split
         windows that exceed this into multiple tapes."""
         p = self.p_active
-        rc = int(host_read(self.state.run_count, self.stats))
-        sc = int(host_read(self.state.stage_count, self.stats))
+        occ = self.scheduler.occupancy()
+        rc, sc = occ.run_count, occ.stage_count
         # mirror ensure_stage_space(): pre-existing full stage seals first
         while sc >= p.Rn:
             if rc >= p.R:
@@ -527,8 +532,7 @@ class SLSM:
                 work.pop(0)
             assert seg, "tape segmentation made no progress"
             seals = TP.tape_seal_bound(
-                self.p_active,
-                int(host_read(self.state.stage_count, self.stats)), seg)
+                self.p_active, self.scheduler.occupancy().stage_count, seg)
             if seals:
                 self.scheduler.reserve_run_slots(seals)
             ops, keys, vals, wts, nv = TP.build_tape(self.p_active, seg)

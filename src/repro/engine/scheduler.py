@@ -41,11 +41,26 @@ Pacing invariants (DESIGN.md §8):
     RuntimeError a few chunks sooner than merge_budget=0 — the remedy is
     the same either way (increase max_levels).
 
+The occupancy lives on the host. The scheduler keeps a mirror of it
+(`MergeScheduler.occupancy`) and moves it by each step's structural
+rule: a seal adds one memory run and takes Rn from the stage, a flush
+moves `runs_merged_eff` memory runs into one level-0 run, a spill moves
+`runs_to_spill` runs of level l into one run of level l+1, a compaction
+leaves the deepest level one run. Only the stage count depends on the
+data (`stage_append` dedups), so a staged chunk costs one device read
+of it. The mirror is keyed on the state object it describes: a state
+the scheduler or the stage loop did not install (a tape's in-scan
+seals, a restore, a replayed retune) makes it stale, and the next read
+rebuilds it from the device (`occupancy_of`, counted in
+``stats["occupancy_resyncs"]``).
+
 Annihilation stays the host decision it was in the synchronous cascade:
 a step elides zero-sum (deleted) keys iff its output becomes the deepest
 data *at the moment the step runs* (paper 2.5/2.8). Each merge step also
-books the Z-set telemetry (rows in/out, annihilated rows) host-side —
-the counts ride occupancy counters the scheduler already reads.
+books the Z-set telemetry (rows in/out, annihilated rows) host-side: a
+flush's rows in are its runs times Rn, and the rows a spill reads and a
+merge writes are read from the device (a flush or spill every 50 or
+more chunks at the paper's geometry).
 
 The adaptive tuner (repro.engine.tuner, DESIGN.md §9) rides this same
 machinery: a decided allocation switch surfaces as a fifth step kind,
@@ -59,7 +74,7 @@ the scheduler is bit-identical to its pre-tuner behaviour.
 """
 from __future__ import annotations
 
-from typing import List, NamedTuple, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -78,15 +93,18 @@ RETUNE = "retune"
 
 
 class Occupancy(NamedTuple):
-    """Host-side occupancy snapshot — all the scheduler ever reads."""
-    stage_count: int
+    """Host-side occupancy of one tree: all the planner looks at. The
+    scheduler's mirror holds ``stage_count=None`` between a staged chunk
+    and the first read of it."""
+    stage_count: Optional[int]
     run_count: int
     level_runs: Tuple[int, ...]   # n_runs per *materialized* level
 
 
 def occupancy_of(state, stats=None) -> Occupancy:
-    """Snapshot a (single-tree) state pytree's occupancy counters: 2 +
-    len(levels) device reads (`host_read`, counted in `stats`)."""
+    """Read a (single-tree) state pytree's occupancy from the device: 2 +
+    len(levels) reads (`host_read`, counted in `stats`). The scheduler
+    builds and rebuilds its mirror with it."""
     return Occupancy(int(host_read(state.stage_count, stats)),
                      int(host_read(state.run_count, stats)),
                      tuple(int(host_read(lv.n_runs, stats))
@@ -196,20 +214,19 @@ def backlog_cost(steps: Sequence[MergeStep]) -> int:
     return sum(s.cost for s in steps)
 
 
-def drop_annihilated_into(state, target_level: int, stats=None) -> bool:
+def drop_annihilated_into(level_runs: Sequence[int],
+                          target_level: int) -> bool:
     """Deletes commit (negative-weight records annihilate) when the merge
-    output becomes the deepest data (paper 2.5/2.8) — evaluated at
-    step-run time, exactly as the synchronous cascade evaluated it at
-    recursion time."""
-    for lv in state.levels[target_level:]:
-        if int(host_read(lv.n_runs, stats)) > 0:
-            return False
-    return True
+    output becomes the deepest data (paper 2.5/2.8): no run rests in
+    `target_level` or below, by the occupancy at step-run time — exactly
+    as the synchronous cascade evaluated it at recursion time."""
+    return not any(level_runs[target_level:])
 
 
 class MergeScheduler:
-    """Single-tree scheduler: owns no array state — it reads the driver's
-    occupancy and executes steps against the driver's state pytree.
+    """Single-tree scheduler: owns no array state — it executes steps
+    against the driver's state pytree and keeps that state's occupancy
+    on the host (`occupancy`).
 
     `on_chunk()` is the one entry point the insert path calls (after each
     staged Rn-chunk): voluntary budgeted steps first, forced chain after.
@@ -218,6 +235,10 @@ class MergeScheduler:
 
     def __init__(self, drv):
         self.drv = drv   # the SLSM driver: .p, .policy, .state, .stats
+        # the occupancy mirror and the state object it describes: it holds
+        # only while that object is still the driver's state
+        self._occ_state = None
+        self._occ: Optional[Occupancy] = None
 
     @property
     def p(self) -> SLSMParams:
@@ -236,6 +257,43 @@ class MergeScheduler:
         tuner = getattr(self.drv, "tuner", None)
         return bool(tuner is not None and tuner.pending)
 
+    # -- the occupancy mirror ----------------------------------------------
+
+    def track(self, occ: Occupancy) -> None:
+        """Make `occ` the mirror of the driver's current state."""
+        self._occ_state, self._occ = self.drv.state, occ
+
+    def _mirror(self) -> Occupancy:
+        """The mirror of the driver's current state (its stage count may
+        be None), rebuilt from the device when the state was replaced by
+        anything but a step or `staged` — counted in the driver's
+        ``occupancy_resyncs``."""
+        drv = self.drv
+        if self._occ_state is not drv.state:
+            self.track(occupancy_of(drv.state, drv.stats))
+            drv.stats["occupancy_resyncs"] += 1
+        return self._occ
+
+    def occupancy(self) -> Occupancy:
+        """The driver state's occupancy, from the host mirror: the only
+        device read it makes is the stage count after a staged chunk
+        (one `host_read`), or a rebuild of a stale mirror."""
+        occ = self._mirror()
+        if occ.stage_count is None:
+            occ = occ._replace(
+                stage_count=self._read(self.drv.state.stage_count))
+            self._occ = occ
+        return occ
+
+    def staged(self, state) -> None:
+        """Install the state a `stage_append` returned. Only the stage
+        count moved, by what the dedup left, so the mirror marks it
+        unknown."""
+        fresh = self._occ_state is self.drv.state
+        self.drv.state = state
+        if fresh:
+            self.track(self._occ._replace(stage_count=None))
+
     # -- step execution (each is one jitted device dispatch) ---------------
 
     def _materialize(self, level: int) -> None:
@@ -243,9 +301,11 @@ class MergeScheduler:
         the paper's unbounded level growth, bounded by max_levels)."""
         drv = self.drv
         while len(drv.state.levels) <= level:
+            occ = self._mirror()
             drv.state = drv.state._replace(
                 levels=drv.state.levels
                 + (empty_level(self.p, len(drv.state.levels)),))
+            self.track(occ._replace(level_runs=occ.level_runs + (0,)))
 
     def _book_merge(self, rows_in: int, rows_out: int) -> None:
         """Z-set merge telemetry (DESIGN.md §13): rows entering the merge
@@ -264,43 +324,50 @@ class MergeScheduler:
 
     def run_step(self, step: MergeStep) -> None:
         """Execute one step as a single jitted device dispatch (or, for
-        RETUNE, the driver's filter-rebuild + active-params swap) and
-        bump the matching stats counter. The one place steps become
-        state transitions — pacing, forcing, and draining all funnel
-        through here; each runs in a ``slsm.step.<kind>`` span."""
+        RETUNE, the driver's filter-rebuild + active-params swap), bump
+        the matching stats counter and move the occupancy mirror by the
+        step's structural rule. The one place steps become state
+        transitions — pacing, forcing, and draining all funnel through
+        here; each runs in a ``slsm.step.<kind>`` span."""
         with jax.profiler.TraceAnnotation(f"slsm.step.{step.kind}"):
             drv, p = self.drv, self.p
+            if step.kind == FLUSH:
+                self._materialize(0)
+            elif step.kind == SPILL:
+                self._materialize(step.level + 1)
+            occ = self.occupancy()
+            runs = list(occ.level_runs)
             if step.kind == RETUNE:
                 drv.apply_retune()
                 drv.stats["retunes"] += 1
             elif step.kind == SEAL:
                 drv.state = seal_run(p, drv.state)
+                occ = occ._replace(stage_count=occ.stage_count - p.Rn,
+                                   run_count=occ.run_count + 1)
                 drv.stats["seals"] += 1
             elif step.kind == FLUSH:
-                self._materialize(0)
-                mr = p.runs_merged_eff
-                rows_in = self._read(jnp.sum(drv.state.buf_counts[:mr]))
-                slot = self._read(drv.state.levels[0].n_runs)
+                mr, slot = p.runs_merged_eff, runs[0]
                 drv.state = merge_buffer_to_level0(
-                    p, drv.state,
-                    drop_annihilated_into(drv.state, 0, drv.stats))
-                self._book_merge(rows_in,
+                    p, drv.state, drop_annihilated_into(runs, 0))
+                # every memory run was sealed full: Rn records each
+                self._book_merge(mr * p.Rn,
                                  self._read(drv.state.levels[0].counts[slot]))
+                occ = occ._replace(run_count=occ.run_count - mr)
+                runs[0] += 1
                 drv.stats["flushes"] += 1
             elif step.kind == SPILL:
-                self._materialize(step.level + 1)
-                n_merge = self.policy.runs_to_spill(
-                    p, self._read(drv.state.levels[step.level].n_runs))
+                lvl = step.level
+                n_merge = self.policy.runs_to_spill(p, runs[lvl])
                 rows_in = self._read(jnp.sum(
-                    drv.state.levels[step.level].counts[:n_merge]))
-                slot = self._read(drv.state.levels[step.level + 1].n_runs)
+                    drv.state.levels[lvl].counts[:n_merge]))
+                slot = runs[lvl + 1]
                 drv.state = merge_level_down(
-                    p, drv.state, step.level, n_merge,
-                    drop_annihilated_into(drv.state, step.level + 1,
-                                          drv.stats))
-                self._book_merge(
-                    rows_in,
-                    self._read(drv.state.levels[step.level + 1].counts[slot]))
+                    p, drv.state, lvl, n_merge,
+                    drop_annihilated_into(runs, lvl + 1))
+                rows_out = self._read(drv.state.levels[lvl + 1].counts[slot])
+                self._book_merge(rows_in, rows_out)
+                runs[lvl] -= n_merge
+                runs[lvl + 1] += 1
                 drv.stats["spills"] += 1
             else:   # COMPACT
                 last = p.max_levels - 1
@@ -310,18 +377,20 @@ class MergeScheduler:
                 # overflow leaves nothing to roll back to: the engine drops
                 # its state and every later call raises the same error
                 drv.state, raw = compact_last_level(p, drv.state)
-                cap = p.level_cap(last)
-                if self._read(raw) > cap:
+                raw, cap = self._read(raw), p.level_cap(last)
+                if raw > cap:
                     drv.state = None
                     drv.state_lost = (
-                        f"sLSM deepest level overflow ({self._read(raw)} > "
+                        f"sLSM deepest level overflow ({raw} > "
                         f"{cap} live elements): increase max_levels beyond "
                         f"{p.max_levels}; this engine's state went into the "
                         f"overflowing compaction, so restore() it from its "
                         f"durability directory")
                     raise RuntimeError(drv.state_lost)
-                self._book_merge(rows_in, self._read(raw))
+                self._book_merge(rows_in, raw)
+                runs[last] = 1
                 drv.stats["compactions"] += 1
+            self.track(occ._replace(level_runs=tuple(runs)))
 
     # -- forced chain (== the legacy synchronous cascade) ------------------
 
@@ -338,7 +407,7 @@ class MergeScheduler:
             self._materialize(level)
             return
         if not self.policy.needs_spill(
-                p, self._read(drv.state.levels[level].n_runs), level):
+                p, self._mirror().level_runs[level], level):
             return
         if level == p.max_levels - 1:
             self.run_step(MergeStep(COMPACT, level,
@@ -353,7 +422,7 @@ class MergeScheduler:
         """Deepest pending step that is ready under the live occupancy
         (None if the backlog is empty or wholly blocked)."""
         p, policy = self.p, self.policy
-        occ = occupancy_of(self.drv.state, self.drv.stats)
+        occ = self.occupancy()
         for step in pending_steps(p, policy, occ, self._retune_pending()):
             if step.ready(occ, p, policy):
                 return step
@@ -375,7 +444,7 @@ class MergeScheduler:
         empty, so a pending retune — like every other piece of
         maintenance in that mode — runs inline, immediately.
 
-        Runs in a ``slsm.schedule`` span: the occupancy reads, the
+        Runs in a ``slsm.schedule`` span: the stage-count read, the
         planning and the steps (each in its own ``slsm.step.<kind>``)."""
         with jax.profiler.TraceAnnotation("slsm.schedule"):
             drv, p = self.drv, self.p
@@ -386,8 +455,7 @@ class MergeScheduler:
                     sampler = getattr(drv, "sample_probe_stats", None)
                     if sampler is not None:
                         sampler()
-            backlog = pending_steps(p, self.policy,
-                                    occupancy_of(drv.state, drv.stats),
+            backlog = pending_steps(p, self.policy, self.occupancy(),
                                     self._retune_pending())
             drv.stats["backlog_peak"] = max(drv.stats["backlog_peak"],
                                             len(backlog))
@@ -420,9 +488,9 @@ class MergeScheduler:
         every mixed-op tape dispatch relies on. This is `on_chunk`'s
         forced tail, callable standalone (the serving layer's headroom
         pass runs it between tapes)."""
-        drv, p = self.drv, self.p
-        while self._read(drv.state.stage_count) >= p.Rn:
-            if self._read(drv.state.run_count) >= p.R:
+        p = self.p
+        while self.occupancy().stage_count >= p.Rn:
+            if self.occupancy().run_count >= p.R:
                 self.force_space(0)
                 self.run_step(MergeStep(FLUSH, -1, step_cost(FLUSH, -1, p)))
             self.run_step(MergeStep(SEAL, -1, step_cost(SEAL, -1, p)))
@@ -439,12 +507,12 @@ class MergeScheduler:
         ``R - that`` (the tape carries too many write keys — split it;
         `SLSM.tape_write_capacity` is the matching key budget)."""
         p = self.p
-        floor = self._read(self.drv.state.run_count) % p.runs_merged_eff
+        floor = self._mirror().run_count % p.runs_merged_eff
         if n > p.R - floor:
             raise ValueError(
                 f"cannot reserve {n} run slots: only {p.R - floor} "
                 f"reachable (R={p.R}, {floor} unflushable resident runs)")
-        while p.R - self._read(self.drv.state.run_count) < n:
+        while p.R - self._mirror().run_count < n:
             self.force_space(0)
             self.run_step(MergeStep(FLUSH, -1, step_cost(FLUSH, -1, p)))
 
@@ -490,10 +558,8 @@ class MergeScheduler:
         readies its shallower dependent. A pending allocation switch
         drains too: after drain() the engine is at rest *under its
         decided allocation*."""
-        drv = self.drv
         while True:
-            backlog = pending_steps(self.p, self.policy,
-                                    occupancy_of(drv.state, drv.stats),
+            backlog = pending_steps(self.p, self.policy, self.occupancy(),
                                     self._retune_pending())
             if not backlog:
                 return
@@ -506,8 +572,7 @@ class MergeScheduler:
     @property
     def backlog(self) -> List[MergeStep]:
         """Current pending steps (introspection/telemetry)."""
-        return pending_steps(self.p, self.policy,
-                             occupancy_of(self.drv.state, self.drv.stats),
+        return pending_steps(self.p, self.policy, self.occupancy(),
                              self._retune_pending())
 
     # -- program warm-up ---------------------------------------------------

@@ -128,23 +128,24 @@ def test_tracing_changes_no_answer_and_no_counter(traced):
 
 
 def test_host_syncs_and_chunks_staged_count_exactly():
-    """On a fresh store (no level yet, so an occupancy read is 2 reads):
-    a full chunk reads the occupancy for the backlog (2) and for the
-    ready step (2), seals, and reads the stage count once (1): 5. A
-    part chunk has no step to run and reads the same 5. The batched
-    lookup copies 2 answer planes, the batched scan 4."""
+    """The scheduler keeps the occupancy on the host: a chunk reads only
+    the stage count its `stage_append` left (1), whether it seals (a full
+    chunk) or not (a part chunk). The batched lookup copies 2 answer
+    planes, the batched scan 4. No step replaced the state behind the
+    scheduler, so the mirror is never rebuilt."""
     store = SLSM(P)
     store.insert(KEYS[:64], KEYS[:64])
     assert (store.stats["host_syncs"], store.stats["chunks_staged"]) == \
-        (5, 1)
+        (1, 1)
     store.insert(KEYS[64:164], KEYS[64:164])
     assert (store.stats["host_syncs"], store.stats["chunks_staged"]) == \
-        (15, 3)
+        (3, 3)
     store.lookup_many(KEYS[:10])
-    assert store.stats["host_syncs"] == 17
+    assert store.stats["host_syncs"] == 5
     store.range_many([(0, 100)])
-    assert store.stats["host_syncs"] == 21
+    assert store.stats["host_syncs"] == 9
     assert store.stats["chunks_staged"] == 3 and store.stats["seals"] == 2
+    assert store.stats["occupancy_resyncs"] == 0
 
 
 @pytest.mark.parametrize("kind", ["insert", "delete"])
@@ -157,4 +158,6 @@ def test_every_write_call_counts_its_chunks(kind):
     else:
         store.delete(keys)
     assert store.stats["chunks_staged"] == 4
-    assert store.stats["host_syncs"] >= 5 * 4
+    # three seals and no flush (R=4): one stage-count read a chunk
+    assert store.stats["seals"] == 3 and store.stats["flushes"] == 0
+    assert store.stats["host_syncs"] == 4
