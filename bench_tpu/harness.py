@@ -7,6 +7,24 @@ each metric is read by ``bench_tpu/metrics/<metric>.py``, whose
 ``read(run)`` returns a number or None. So a new cell needs new files
 and a `BENCHMARK.json` entry, and no edit here.
 
+A configuration file states the store it deploys (`engine_settings`).
+Its top-level keys are:
+
+* any field of `repro.core.params.SLSMParams` (``eps_per_level`` as a
+  list, ``tuning`` as an object of `TuningPolicy` fields);
+* ``policy``: the compaction policy, ``{"name": <CompactionPolicy.name>,
+  ...its constructor's arguments}``, e.g. ``{"name": "leveling",
+  "max_resident": 2}``; tiering where it is left out;
+* ``durability``: null, or ``{"fsync": bool, "snapshot_every_bytes":
+  n}`` for a group-commit write-ahead log;
+* ``key_universe`` and ``preload_keys``, ``preload_overwrites``,
+  ``preload_deletes``, ``preload_call`` (`bench_tpu.traffic.Data`);
+* documentation that no run reads: ``published``, ``reduced``,
+  ``assumed``, ``guarantees``, ``deployment``, ``reference``.
+
+Any other key is refused (`BenchError`), so that a file cannot state a
+store that the run does not build.
+
 A run (`run_cell`): build the store, warm its programs, preload the
 seed's data, warm the traffic, measure for the given seconds, then check
 every answer of the window and a read-back of the written keys against
@@ -37,9 +55,12 @@ READBACK_BATCH = 4096
 # tape slot buckets warmed for served cells: every window of up to 512
 # coalesced chunks runs a program that was compiled and traced in set-up
 TAPE_WARM = (4, 16, 64, 128, 256, 512)
-# SLSMParams fields a configuration file states at its top level
-ENGINE_KEYS = ("R", "Rn", "eps", "D", "m", "mu", "max_levels",
-               "merge_budget", "range_cand", "max_range", "backend")
+# top-level keys of a configuration file that are not `SLSMParams`
+# fields: read by the harness and the generator, or documentation
+HARNESS_KEYS = frozenset({
+    "key_universe", "preload_keys", "preload_overwrites", "preload_deletes",
+    "preload_call", "durability", "policy", "published", "reduced",
+    "assumed", "guarantees", "deployment", "reference"})
 # the numbers `correct` is decided by; every limit is 0 (exact answers)
 CHECKS = ("wrong_answers", "truncated_answers", "wrong_readback",
           "unanswered", "writes_miscounted")
@@ -75,7 +96,9 @@ class Registry:
         return self._entry("workloads", name)
 
     def config(self, name: str) -> dict:
-        return load_json(self.root / self._entry("configs", name)["file"])
+        config = load_json(self.root / self._entry("configs", name)["file"])
+        engine_settings(config)
+        return config
 
     def traffic(self, name: str) -> dict:
         return load_json(HERE / "traffic" / f"{name}.json")
@@ -163,24 +186,59 @@ def peaks(device_kind: str) -> dict:
     return table["devices"][device_kind]
 
 
+def engine_settings(config: dict):
+    """(SLSMParams, compaction policy) that `config` states. Raises
+    `BenchError` for a key that is neither an `SLSMParams` field nor in
+    `HARNESS_KEYS`, and for a policy the engine does not have."""
+    import dataclasses
+
+    from repro.core.params import SLSMParams
+    from repro.engine import CompactionPolicy, TieringPolicy
+    from repro.engine import wal as WAL
+
+    fields = {f.name for f in dataclasses.fields(SLSMParams)}
+    unknown = sorted(set(config) - fields - HARNESS_KEYS)
+    if unknown:
+        raise BenchError(f"configuration keys {unknown} are neither "
+                         f"SLSMParams fields nor read by the harness")
+    params = WAL.params_from_dict(
+        {k: v for k, v in config.items() if k in fields})
+    spec = config.get("policy")
+    if spec is None:
+        return params, TieringPolicy()
+    if not isinstance(spec, dict):
+        raise BenchError(f"policy {spec!r} is not an object with a name")
+    spec = dict(spec)
+    name = spec.pop("name", None)
+    known = {c.name: c for c in CompactionPolicy.__subclasses__()}
+    if name not in known:
+        raise BenchError(f"unknown compaction policy {name!r}; the engine "
+                         f"has {sorted(known)}")
+    try:
+        policy = known[name](**spec)
+    except TypeError as e:
+        raise BenchError(f"compaction policy {name!r}: {e}") from e
+    policy.validate(params)
+    return params, policy
+
+
 def build_store(config: dict, wal_dir: str | None, control: bool):
     """The system under test at `config` (or the control in its place)."""
+    params, policy = engine_settings(config)
     space = T.KeySpace(config["key_universe"])
     if control:
         from bench_tpu.control import FirstWriteStore
         return FirstWriteStore(space, int(config["max_range"]))
-    from repro.core.params import SLSMParams
-    from repro.engine import SLSM, TieringPolicy
+    from repro.engine import SLSM
     from repro.engine import wal as WAL
 
-    params = SLSMParams(**{k: config[k] for k in ENGINE_KEYS})
     dur = config.get("durability")
     durability = None
     if dur is not None:
         durability = WAL.Durability(
             wal_dir, fsync=bool(dur["fsync"]),
             snapshot_every_bytes=int(dur["snapshot_every_bytes"]))
-    return SLSM(params, policy=TieringPolicy(), durability=durability)
+    return SLSM(params, policy=policy, durability=durability)
 
 
 def build_server(store, control: bool):

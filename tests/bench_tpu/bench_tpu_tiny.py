@@ -1,6 +1,7 @@
 """Tiny sizes for running the benchmark's cells on the CPU in tests."""
 from __future__ import annotations
 
+import json
 import sys
 from pathlib import Path
 
@@ -10,8 +11,11 @@ if str(ROOT) not in sys.path:
 
 from bench_tpu import harness as H  # noqa: E402
 
-CELLS = ("ingest.s3-durable", "lookup.s3-volatile", "scan.s3-volatile",
-         "serve-a.s3-durable")
+SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+# every cell of the benchmark, in file order, and the served YCSB-A mix:
+# out of the benchmark, it keeps the harness's open loop under test
+CELLS = tuple(dict.fromkeys(
+    [w["name"] for w in SPEC["workloads"]] + ["serve-a.s3-durable"]))
 
 # the cells' geometry cut to a few hundred keys: the deepest level holds
 # 4,096 keys, twice the universe, as the chip's holds twice its 8M
@@ -24,25 +28,37 @@ TINY_BATCH = {"insert": 500, "lookup": 256, "range": 8}
 
 
 def tiny_cell(cell: str):
-    """(config, traffic) of `cell` (``<traffic>.<config>``) at tiny
-    sizes."""
+    """(config, traffic) of `cell` at tiny sizes: the benchmark's entry
+    of that name, else ``<traffic>.<config>``. The configuration keeps
+    every key that `TINY_CONFIG` does not set (its policy, its other
+    engine fields)."""
     reg = H.Registry()
-    traffic_name, config_name = cell.split(".", 1)
+    try:
+        w = reg.cell(cell)
+        traffic_name, config_name = w["traffic"], w["config"]
+    except H.BenchError:
+        traffic_name, config_name = cell.split(".", 1)
     config = dict(reg.config(config_name), **TINY_CONFIG)
     traffic = dict(reg.traffic(traffic_name))
     if traffic["loop"] == "closed":
+        if traffic["op"] not in TINY_BATCH:
+            raise ValueError(f"no tiny batch for closed-loop op "
+                             f"{traffic['op']!r} of {cell!r}")
         traffic["batch"] = TINY_BATCH[traffic["op"]]
         traffic["warm_calls"] = 1
-    else:
+    elif traffic["loop"] == "open":
         traffic["rate_per_s"] = 200
         traffic["warm_s"] = 0.5
+    else:
+        raise ValueError(f"unknown loop {traffic['loop']!r} of {cell!r}")
     return config, traffic
 
 
-def run_tiny(cell: str, seed: int = 2**31 + 7, control: bool = False,
-             seconds: float = 0.5):
-    """One CPU run of `cell` at tiny sizes; returns (run, checks)."""
-    config, traffic = tiny_cell(cell)
+def run_config(cell: str, config: dict, traffic: dict,
+               seed: int = 2**31 + 7, control: bool = False,
+               seconds: float = 0.5):
+    """One CPU run of `cell` at `config` and `traffic`; returns (run,
+    checks)."""
     saved = H.TAPE_WARM
     H.TAPE_WARM = (4, 16, 64)
     try:
@@ -50,6 +66,13 @@ def run_tiny(cell: str, seed: int = 2**31 + 7, control: bool = False,
                           control=control)
     finally:
         H.TAPE_WARM = saved
+
+
+def run_tiny(cell: str, seed: int = 2**31 + 7, control: bool = False,
+             seconds: float = 0.5):
+    """One CPU run of `cell` at tiny sizes; returns (run, checks)."""
+    return run_config(cell, *tiny_cell(cell), seed=seed, control=control,
+                      seconds=seconds)
 
 
 def correct(run, checks) -> bool:

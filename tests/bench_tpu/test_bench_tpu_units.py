@@ -10,7 +10,7 @@ import sys
 import numpy as np
 import pytest
 
-from bench_tpu_tiny import CELLS, ROOT, TINY_CONFIG, tiny_cell
+from bench_tpu_tiny import CELLS, ROOT, TINY_BATCH, TINY_CONFIG, tiny_cell
 from bench_tpu import harness as H
 from bench_tpu import traffic as T
 from bench_tpu import xplane
@@ -129,11 +129,20 @@ def test_reference_matches_a_dict_with_duplicates_in_a_call():
 
 def test_every_name_is_found():
     reg = H.Registry()
-    assert {w["name"] for w in SPEC["workloads"]} <= set(CELLS)
+    names = [w["name"] for w in SPEC["workloads"]]
+    assert list(CELLS[:len(names)]) == names
     for w in SPEC["workloads"]:
         assert w["name"] == f"{w['traffic']}.{w['config']}"
         reg.config(w["config"])
         reg.traffic(w["traffic"])
+        # every cell runs in the CPU tests at tiny sizes, as it is stated
+        config, traffic = tiny_cell(w["name"])
+        H.engine_settings(config)
+        assert {k: config[k] for k in TINY_CONFIG} == TINY_CONFIG
+        if traffic["loop"] == "closed":
+            assert traffic["batch"] == TINY_BATCH[traffic["op"]]
+        else:
+            assert traffic["rate_per_s"] == 200
         e2e = reg.metrics(w["name"], "end_to_end")
         names = {m["name"] for m in e2e}
         assert "setup_s" in names and len(names) >= 2
@@ -179,7 +188,9 @@ def test_benchmark_file_keeps_its_contract():
                        for e in SPEC["end_to_end"]
                        if e["name"] == m["moves"])
     for w in SPEC["workloads"]:
-        assert len(w["why"]) <= 200 and w["chips"] == 1
+        assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
+    fours = sum(w["chips"] == 4 for w in SPEC["workloads"])
+    assert fours <= max(1, len(SPEC["workloads"]) // 2)
 
 
 def test_open_loop_times_requests_from_when_they_were_due():
